@@ -31,6 +31,9 @@ def peak_rss_of(snippet: str) -> float:
     env = dict(os.environ)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = os.path.join(root, "src")
+    # the child measures host memory only; on the CPU it never competes
+    # with this process for the accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", prog], env=env,
                          capture_output=True, text=True, check=True)
     for line in out.stdout.splitlines():

@@ -55,6 +55,8 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks.check import main as check_main
     if "--check" in sys.argv[1:]:
         sys.exit(check_main(sys.argv[1:]))

@@ -256,10 +256,9 @@ class EncodePipeline:
                     pending.append(ex.submit(tok, spans[self.depth + i]))
                 yield from emit(span, enc)
 
-    def jit_cache_size(self) -> int | None:
-        """Compiled-executable count straight from jax (when exposed)."""
-        cache_size = getattr(self._jit, "_cache_size", None)
-        return cache_size() if callable(cache_size) else None
+    def jit_cache_size(self) -> int:
+        """Compiled-executable count straight from jax."""
+        return self._jit._cache_size()
 
 
 class PipelineChunkSource:

@@ -228,12 +228,24 @@ class RetrievalEvaluator:
         self.fault_injector = fault_injector
         self.retriever = retriever
         self.collator = collator
-        self.params = params
         self.mesh = mesh
         self.process_index = (jax.process_index() if process_index is None
                               else process_index)
         self.process_count = (jax.process_count() if process_count is None
                               else process_count)
+        # worker r of an in-process cluster (SimulatedCluster) owns local
+        # chip r when the host has a chip per worker: its encoder
+        # weights, prepared corpus, query embeddings and heap state all
+        # live there.  Otherwise (one worker, a real jax.distributed
+        # rank, or more workers than chips) everything stays on the
+        # default device.
+        local = jax.local_devices()
+        self.device = (local[self.process_index]
+                       if jax.process_count() == 1
+                       and 1 < self.process_count <= len(local) else None)
+        if self.device is not None:
+            params = jax.device_put(params, self.device)
+        self.params = params
         # pass a shared FairSharder (e.g. SimulatedCluster.sharder) so all
         # workers of one cluster see the same throughput-EMA state
         self.sharder = (FairSharder(self.process_count) if sharder is None
@@ -415,9 +427,10 @@ class RetrievalEvaluator:
                                      device_resident=device_resident)
 
         if device_resident:
-            embs = self.encode_corpus(all_hashes, corpus_texts, cache)
-            arr = jnp.asarray(embs, jnp.float32) if on_device \
-                else np.asarray(embs, np.float32)
+            embs = np.asarray(
+                self.encode_corpus(all_hashes, corpus_texts, cache),
+                np.float32)
+            arr = jax.device_put(embs, self.device) if on_device else embs
             return PreparedCorpus(all_hashes, n_docs,
                                   lambda lo, hi: arr[lo:hi])
 
@@ -527,8 +540,8 @@ class RetrievalEvaluator:
             def get_range(lo, hi):
                 return embs[lo:hi]
 
-            arr = (jnp.asarray(embs) if device_resident and on_device
-                   else embs)
+            arr = (jax.device_put(embs, self.device)
+                   if device_resident and on_device else embs)
 
             def fetch_rows(rows):
                 return arr[rows]

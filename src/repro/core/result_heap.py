@@ -93,6 +93,8 @@ class FastResultHeapq:
             self._heaps: list[list[tuple[float, int]]] = [
                 [] for _ in range(n_queries)]
         else:
+            # uncommitted: the first update moves them to the scores'
+            # device
             self.vals = jnp.full((n_queries, k), NEG_INF, jnp.float32)
             self.ids = jnp.full((n_queries, k), -1, jnp.int32)
 

@@ -430,15 +430,18 @@ class ShardedSearchDriver:
         n_q, dim = q_emb.shape
         c = self.chunk_size
         merge = "pallas" if self.heap_impl == "pallas" else "jax"
+        # uploaded tiles and the (Q, k) state follow the query embeddings
+        # onto their device (the worker's chip); host queries: default
+        dev = (next(iter(q_emb.devices())) if isinstance(q_emb, jax.Array)
+               else None)
         pad_rows = (-n_q) % 8
-        if isinstance(q_emb, np.ndarray):
-            qp = np.pad(q_emb, ((0, pad_rows), (0, 0))) if pad_rows \
-                else q_emb
-        else:
-            qp = jnp.pad(q_emb, ((0, pad_rows), (0, 0))) if pad_rows \
-                else q_emb
-        state_v = jnp.full((n_q + pad_rows, topk), -jnp.inf, jnp.float32)
-        state_i = jnp.full((n_q + pad_rows, topk), -1, jnp.int32)
+        qp = jax.device_put(q_emb, dev)
+        if pad_rows:
+            qp = jnp.pad(qp, ((0, pad_rows), (0, 0)))
+        state_v = jnp.full((n_q + pad_rows, topk), -jnp.inf, jnp.float32,
+                           device=dev)
+        state_i = jnp.full((n_q + pad_rows, topk), -1, jnp.int32,
+                           device=dev)
         dispatches = 0
 
         def flush(buf):
@@ -449,9 +452,10 @@ class ShardedSearchDriver:
                 offs[si] = off
                 nvs[si] = embs.shape[0]
             if all(isinstance(e, np.ndarray) for _, e in buf):
-                tile = np.zeros((s, c, dim), np.float32)
+                host = np.zeros((s, c, dim), np.float32)
                 for si, (_, embs) in enumerate(buf):
-                    tile[si, :embs.shape[0]] = embs
+                    host[si, :embs.shape[0]] = embs
+                tile = jax.device_put(host, dev)
             else:           # device-resident chunks (online encode path)
                 parts = []
                 for _, embs in buf:
@@ -459,7 +463,8 @@ class ShardedSearchDriver:
                     if e.shape[0] < c:
                         e = jnp.pad(e, ((0, c - e.shape[0]), (0, 0)))
                     parts.append(e)
-                parts += [jnp.zeros((c, dim), jnp.float32)] * (s - len(buf))
+                parts += [jnp.zeros((c, dim), jnp.float32, device=dev)
+                          ] * (s - len(buf))
                 tile = jnp.stack(parts)
             state_v, state_i = kops.superchunk_update(
                 state_v, state_i, qp, tile, offs, nvs, k=topk,

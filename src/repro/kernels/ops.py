@@ -14,7 +14,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import topk as _topk
-from repro.kernels import embedding_bag as _bag
 
 
 def _default_interpret() -> bool:
@@ -184,22 +183,3 @@ def superchunk_update(vals, ids, queries, tile, offsets, n_valids, *,
         jnp.asarray(offsets, jnp.int32), jnp.asarray(n_valids, jnp.int32),
         k, score, merge, interpret)
 
-
-@functools.partial(jax.jit, static_argnames=("bq", "interpret"))
-def _bag_jit(table, idx, weights, bq, interpret):
-    return _bag.embedding_bag_pallas(
-        table, idx, weights, bq=bq, interpret=interpret)
-
-
-def embedding_bag(table, idx, weights=None, *, bq: int = 256,
-                  interpret: bool | None = None):
-    """Fused gather+reduce EmbeddingBag; idx < 0 = padding."""
-    interpret = _default_interpret() if interpret is None else interpret
-    b = idx.shape[0]
-    idx_p = _pad_axis(jnp.asarray(idx, jnp.int32), 0, 8, -1)
-    if weights is not None:
-        weights = _pad_axis(jnp.asarray(weights), 0, 8, 0.0)
-    else:
-        weights = jnp.ones(idx_p.shape, table.dtype)
-    out = _bag_jit(table, idx_p, weights, bq, interpret)
-    return out[:b]

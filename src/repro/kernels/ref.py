@@ -33,17 +33,3 @@ def fused_score_topk_ref(queries: jax.Array, docs: jax.Array, k: int,
     top_v, pos = jax.lax.top_k(scores, k)
     return top_v, (pos + id_offset).astype(jnp.int32)
 
-
-def embedding_bag_ref(table: jax.Array, idx: jax.Array,
-                      weights: jax.Array | None = None):
-    """Bagged embedding sum: table (V,D), idx (B, L) -> (B, D).
-
-    idx < 0 entries are masked out (padding); optional per-sample weights.
-    """
-    mask = (idx >= 0)
-    safe = jnp.maximum(idx, 0)
-    rows = jnp.take(table, safe, axis=0)              # (B, L, D)
-    w = mask.astype(table.dtype)
-    if weights is not None:
-        w = w * weights.astype(table.dtype)
-    return jnp.sum(rows * w[..., None], axis=1)

@@ -29,27 +29,40 @@ NEG_INF = float("-inf")
 
 
 def _select_topk_into(out_v_ref, out_i_ref, cand_v, cand_i, k: int):
-    """Iteratively select the k largest (value, id) pairs of cand into refs."""
+    """Iteratively select the k largest (value, id) pairs of cand into refs.
+
+    The (bq, k) results ride the loop carry and each ref is written once
+    after the loop: Mosaic refuses a store at a dynamic lane offset
+    (``ref[:, pl.ds(j, 1)]``), so rank ``j`` fills its column by a select
+    against a lane iota instead.
+    """
+    bq = cand_v.shape[0]
+    rank = jax.lax.broadcasted_iota(jnp.int32, (bq, k), 1)
 
     def body(j, carry):
-        cv, ci = carry
+        cv, out_v, out_i = carry
         m = jnp.max(cv, axis=1)                                   # (bq,)
         iota = jax.lax.broadcasted_iota(jnp.int32, cv.shape, 1)
         at_max = cv == m[:, None]
         first = jnp.min(jnp.where(at_max, iota, cv.shape[1]), axis=1)
         onehot = iota == first[:, None]
-        sel_id = jnp.max(jnp.where(onehot, ci, -1), axis=1)
+        sel_id = jnp.max(jnp.where(onehot, cand_i, -1), axis=1)
         # -inf means "empty / never retrieve": emit -1, not the id.  The
         # NEG_INF mask below can't distinguish an already-selected
         # position from a genuinely empty one — without this, once the
         # running max hits -inf the first selected position would be
         # re-picked and re-emit its real id (duplicate ids in the tail).
         sel_id = jnp.where(m == NEG_INF, -1, sel_id)
-        out_v_ref[:, pl.ds(j, 1)] = m[:, None]
-        out_i_ref[:, pl.ds(j, 1)] = sel_id[:, None]
-        return jnp.where(onehot, NEG_INF, cv), ci
+        col = rank == j
+        out_v = jnp.where(col, m[:, None], out_v)
+        out_i = jnp.where(col, sel_id[:, None], out_i)
+        return jnp.where(onehot, NEG_INF, cv), out_v, out_i
 
-    jax.lax.fori_loop(0, k, body, (cand_v, cand_i))
+    _, out_v, out_i = jax.lax.fori_loop(
+        0, k, body, (cand_v, jnp.full((bq, k), NEG_INF, jnp.float32),
+                     jnp.full((bq, k), -1, jnp.int32)))
+    out_v_ref[...] = out_v
+    out_i_ref[...] = out_i
 
 
 def _topk_update_kernel(vals_ref, ids_ref, scores_ref, cids_ref,

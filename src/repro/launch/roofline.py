@@ -127,16 +127,7 @@ def collective_breakdown(hlo_text: str, top: int = 8):
 
 
 def normalize_cost(cost) -> dict:
-    """Version-tolerant ``compiled.cost_analysis()`` result -> flat dict.
-
-    Newer JAX returns the properties dict directly; older releases return
-    a one-element list of per-computation dicts (summed here)."""
-    if isinstance(cost, (list, tuple)):
-        merged: dict = {}
-        for c in cost:
-            for k, v in (c or {}).items():
-                merged[k] = merged.get(k, 0.0) + v
-        return merged
+    """``compiled.cost_analysis()`` (a dict, or None) -> flat dict."""
     return dict(cost or {})
 
 

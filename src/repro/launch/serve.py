@@ -31,6 +31,60 @@ import threading
 import time
 
 
+def warm_rungs(frontend, texts, max_batch: int, min_batch: int = 1) -> float:
+    """Serve one micro-batch at every power-of-two rung from
+    ``min_batch`` up to ``max_batch`` (and ``max_batch`` itself), so every
+    encode and scoring shape a coalesced flush can hit is compiled before
+    timing starts.  Returns the seconds it took."""
+    t0 = time.monotonic()
+    widths, b = [], min_batch
+    while b < max_batch:
+        widths.append(b)
+        b *= 2
+    widths.append(max_batch)
+    for w in widths:
+        frontend.search([texts[j % len(texts)] for j in range(w)])
+    return time.monotonic() - t0
+
+
+def run_requests(frontend, requests, *, concurrency: int = 1,
+                 deadline_ms: float | None = None):
+    """Submit every request (a list of query texts) from ``concurrency``
+    threads, retrying on overload, and wait for all of them.  Returns
+    ``(outputs, latencies_s, loop_s)``: each request's ``(ids, scores)``
+    result in request order, its submit-to-result seconds, and the wall
+    time of the whole loop."""
+    from repro.core.serving import ServeOverloadError
+
+    outputs = [None] * len(requests)
+    latencies = [0.0] * len(requests)
+
+    def submit_one(i: int) -> None:
+        t0 = time.monotonic()
+        while True:
+            try:
+                fut = frontend.submit(requests[i], deadline_ms=deadline_ms)
+                break
+            except ServeOverloadError:
+                time.sleep(0.001)      # accepted-or-retried, never dropped
+        out = fut.result()
+        ids, _ = out
+        assert ids.shape == (len(requests[i]), frontend.topk), ids.shape
+        latencies[i] = time.monotonic() - t0
+        outputs[i] = out
+
+    t_loop = time.monotonic()
+    if concurrency > 1:
+        from concurrent.futures import ThreadPoolExecutor
+        with ThreadPoolExecutor(concurrency,
+                                thread_name_prefix="serve-client") as pool:
+            list(pool.map(submit_one, range(len(requests))))
+    else:
+        for i in range(len(requests)):
+            submit_one(i)
+    return outputs, latencies, time.monotonic() - t_loop
+
+
 def main(argv=None):
     import jax
     import numpy as np
@@ -39,7 +93,7 @@ def main(argv=None):
     from repro.core.config import DataArguments, EvaluationArguments
     from repro.core.embedding_cache import EmbeddingCache
     from repro.core.evaluator import RetrievalEvaluator
-    from repro.core.serving import ServeFrontend, ServeOverloadError
+    from repro.core.serving import ServeFrontend
     from repro.configs import get_arch
     from repro.data.synthetic import make_retrieval_dataset
     from repro.data.tokenizer import HashTokenizer
@@ -223,34 +277,10 @@ def main(argv=None):
     # coalesced micro-batch can hit (a micro-batch of Q queries pads to
     # the next rung <= max_batch), so the request loop below measures
     # steady-state serving latency only -------------------------------------
-    t_warm = time.monotonic()
-    all_texts = [queries[q] for q in q_ids]
-    warm_widths, b = [], 1
-    while b < args.max_batch:
-        warm_widths.append(b)
-        b *= 2
-    warm_widths.append(args.max_batch)
-    for w in warm_widths:
-        frontend.search([all_texts[j % len(all_texts)] for j in range(w)])
-    warm_s = time.monotonic() - t_warm
+    warm_s = warm_rungs(frontend, [queries[q] for q in q_ids],
+                        args.max_batch)
     print(f"prepared corpus ({len(corpus)} docs, cache {len(cache)} rows) "
           f"in {prep_s:.2f}s; warm pass {warm_s * 1e3:.1f} ms on {label}")
-
-    # -- steady-state request loop ------------------------------------------
-    latencies = [0.0] * args.n_requests
-
-    def submit_one(i: int) -> None:
-        t0 = time.monotonic()
-        while True:
-            try:
-                fut = frontend.submit(requests[i],
-                                      deadline_ms=args.deadline_ms)
-                break
-            except ServeOverloadError:
-                time.sleep(0.001)      # accepted-or-retried, never dropped
-        ids, scores = fut.result()
-        assert ids.shape == (args.batch, args.topk), ids.shape
-        latencies[i] = time.monotonic() - t0
 
     # -- live-corpus writer (--mutate): adds, updates, deletes, and one
     # online compaction run concurrently with the request loop; serving
@@ -293,16 +323,9 @@ def main(argv=None):
                                       name="serve-mutate", daemon=True)
         mut_thread.start()
 
-    t_loop = time.monotonic()
-    if args.concurrency > 1:
-        from concurrent.futures import ThreadPoolExecutor
-        with ThreadPoolExecutor(args.concurrency,
-                                thread_name_prefix="serve-client") as pool:
-            list(pool.map(submit_one, range(args.n_requests)))
-    else:
-        for i in range(args.n_requests):
-            submit_one(i)
-    loop_s = time.monotonic() - t_loop
+    _, latencies, loop_s = run_requests(
+        frontend, requests, concurrency=args.concurrency,
+        deadline_ms=args.deadline_ms)
     if mut_thread is not None:
         stop_mut.set()
         mut_thread.join()
@@ -352,4 +375,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

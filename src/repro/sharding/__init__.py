@@ -1,35 +1,22 @@
 """Sharding utilities: mesh construction + logical-axis partitioning.
 
-``make_mesh`` is the version-tolerant mesh constructor: newer JAX
-releases accept (and some sharding passes want) ``axis_types``, while
-older releases have neither ``jax.sharding.AxisType`` nor the
-``axis_types`` kwarg on ``jax.make_mesh``.  All mesh construction in the
-repo goes through here so the JAX version is probed in exactly one place.
+All mesh construction in the repo goes through ``make_mesh`` so every
+mesh axis gets the same (``Auto``) sharding type.
 """
 
 from __future__ import annotations
 
-import inspect
 from typing import Sequence
 
 import jax
 
-_MAKE_MESH_TAKES_AXIS_TYPES = (
-    "axis_types" in inspect.signature(jax.make_mesh).parameters)
-
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str],
               *, axis_types=None) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` wrapper tolerant of pre-``AxisType`` JAX.
-
-    When the installed JAX supports axis types, every axis defaults to
-    ``AxisType.Auto`` (the sharding behaviour older releases implement
-    unconditionally); otherwise the kwarg is dropped.
-    """
+    """``jax.make_mesh`` with every axis ``AxisType.Auto`` unless
+    ``axis_types`` says otherwise."""
     shape = tuple(shape)
     axes = tuple(axes)
-    if not _MAKE_MESH_TAKES_AXIS_TYPES:
-        return jax.make_mesh(shape, axes)
-    if axis_types is None and hasattr(jax.sharding, "AxisType"):
+    if axis_types is None:
         axis_types = (jax.sharding.AxisType.Auto,) * len(axes)
     return jax.make_mesh(shape, axes, axis_types=axis_types)

@@ -194,14 +194,11 @@ def test_superchunk_update_traced_offsets_no_recompile():
     v, i = ops.superchunk_update(
         v, i, q, tile, np.arange(0, 128, 32, dtype=np.int32),
         np.full(4, 32, np.int32), k=5)
-    before = (ops._superchunk_scan_jit._cache_size()
-              if hasattr(ops._superchunk_scan_jit, "_cache_size")
-              else None)
+    before = ops._superchunk_scan_jit._cache_size()
     v, i = ops.superchunk_update(
         v, i, q, tile, np.arange(1000, 1128, 32, dtype=np.int32),
         np.full(4, 32, np.int32), k=5)
-    if before is not None:
-        assert ops._superchunk_scan_jit._cache_size() == before
+    assert ops._superchunk_scan_jit._cache_size() == before
 
 
 def test_superchunk_update_masks_padded_steps():
