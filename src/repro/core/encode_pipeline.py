@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import tracing
 from repro.data.tokenizer import HashTokenizer, pad_token_rows
 
 
@@ -95,8 +96,7 @@ class EncodePipeline:
         self.batch_size = max(batch_size, 1)
         self.tokenizer_workers = max(tokenizer_workers, 1)
         self.depth = max(depth, 0)
-        self.stats = {"compiles": 0, "batches": 0, "tokens_real": 0,
-                      "tokens_padded": 0, "windows": 0}
+        self.stats = {"compiles": 0, "tokens_real": 0, "tokens_padded": 0}
         self._ladders: dict[int, tuple[int, ...]] = {}
 
         def _traced(params, tokens, mask):
@@ -117,22 +117,24 @@ class EncodePipeline:
                  fmt: Callable[[str], str] | None = None
                  ) -> list[list[int]]:
         """Token-id rows for ``texts``, fanned over the tokenizer pool."""
-        texts = [fmt(t) for t in texts] if fmt is not None else list(texts)
-        if (self.tokenizer_workers <= 1
-                or len(texts) < 4 * self.tokenizer_workers):
-            return self.tokenizer.batch_encode_ids(texts, max_len,
-                                                   self.append_eos)
-        step = -(-len(texts) // self.tokenizer_workers)
-        # a per-call pool (like stream()'s tokenize-ahead pool): spawn
-        # cost is microseconds against a window of tokenization, and no
-        # idle threads outlive the call
-        with ThreadPoolExecutor(self.tokenizer_workers,
-                                thread_name_prefix="tokenize") as pool:
-            parts = list(pool.map(
-                lambda lo: self.tokenizer.batch_encode_ids(
-                    texts[lo: lo + step], max_len, self.append_eos),
-                range(0, len(texts), step)))
-        return [row for part in parts for row in part]
+        with tracing.span("trove.encode.tokenize", n=len(texts)):
+            texts = ([fmt(t) for t in texts] if fmt is not None
+                     else list(texts))
+            if (self.tokenizer_workers <= 1
+                    or len(texts) < 4 * self.tokenizer_workers):
+                return self.tokenizer.batch_encode_ids(texts, max_len,
+                                                       self.append_eos)
+            step = -(-len(texts) // self.tokenizer_workers)
+            # a per-call pool (like stream()'s tokenize-ahead pool): spawn
+            # cost is microseconds against a window of tokenization, and
+            # no idle threads outlive the call
+            with ThreadPoolExecutor(self.tokenizer_workers,
+                                    thread_name_prefix="tokenize") as pool:
+                parts = list(pool.map(
+                    lambda lo: self.tokenizer.batch_encode_ids(
+                        texts[lo: lo + step], max_len, self.append_eos),
+                    range(0, len(texts), step)))
+            return [row for part in parts for row in part]
 
     # -- stage 2: shape bucketing ---------------------------------------------
     def ladder(self, max_len: int) -> tuple[int, ...]:
@@ -181,22 +183,21 @@ class EncodePipeline:
         parts, perm = [], []
         for lo in range(0, n, b):
             idx = order[lo: lo + b]
-            rows = [enc[i] for i in idx]
             rung = self._fit(max(lengths[idx].max(), 1), ladder)
-            toks, mask = pad_token_rows(rows, rung, self.tokenizer.pad_id,
-                                        n_rows=b)
-            out = self._jit(params, toks, mask)
+            with tracing.span("trove.encode.run", rung=rung):
+                toks, mask = pad_token_rows([enc[i] for i in idx], rung,
+                                            self.tokenizer.pad_id, n_rows=b)
+                out = self._jit(params, toks, mask)
             parts.append(out[: len(idx)])
             perm.append(idx)
-            self.stats["batches"] += 1
             self.stats["tokens_real"] += int(lengths[idx].sum())
             self.stats["tokens_padded"] += b * rung
         inverse = np.empty(n, np.int64)
         inverse[np.concatenate(perm)] = np.arange(n)
-        self.stats["windows"] += 1
         if device:
             return jnp.concatenate(parts)[jnp.asarray(inverse)]
-        return np.concatenate([np.asarray(p) for p in parts])[inverse]
+        with tracing.span("trove.encode.fetch"):
+            return np.concatenate([np.asarray(p) for p in parts])[inverse]
 
     # -- public API -----------------------------------------------------------
     def encode(self, params, texts: Sequence[str], max_len: int, *,

@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import tracing
 from repro.core.config import EvaluationArguments
 from repro.core.embedding_cache import EmbeddingCache
 from repro.core.encode_pipeline import EncodePipeline, PipelineChunkSource
@@ -194,15 +195,17 @@ class IVFPreparedCorpus(PreparedCorpus):
         self.nprobe = int(nprobe)
 
     def round_for(self, q_emb):
-        q = np.asarray(q_emb, np.float32)
-        clusters = self.index.select(q, self.nprobe)
-        sel_rows = self.index.gather_rows(clusters)
-        sized = IVFSearchSpace(len(sel_rows),
-                               self.index.slice_boundaries(clusters))
+        with tracing.span("trove.ivf.select"):
+            q = np.asarray(q_emb, np.float32)
+            clusters = self.index.select(q, self.nprobe)
+            sel_rows = self.index.gather_rows(clusters)
+            sized = IVFSearchSpace(len(sel_rows),
+                                   self.index.slice_boundaries(clusters))
         fetch = self.fetch_rows
 
         def load_chunk(lo: int, hi: int):
-            return fetch(sel_rows[lo:hi])
+            with tracing.span("trove.ivf.gather"):
+                return fetch(sel_rows[lo:hi])
 
         def positions_to_ids(pos: np.ndarray) -> np.ndarray:
             if len(sel_rows) == 0:
@@ -345,7 +348,8 @@ class RetrievalEvaluator:
             embs = np.empty((len(ids), enc.shape[1]), np.float32)
             embs[missing] = enc
             if cache is not None:
-                cache.cache_records([ids[i] for i in missing], enc)
+                with tracing.span("trove.cache.write", n=len(missing)):
+                    cache.cache_records([ids[i] for i in missing], enc)
         if have.any():
             got = cache.get([ids[i] for i in np.nonzero(have)[0]])
             if embs.shape[1] == 0:

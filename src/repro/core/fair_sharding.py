@@ -77,6 +77,10 @@ class FairSharder:
         self._issued = [0] * n_workers       # rounds begun, per worker
         # round -> agreed corpus generation key (first acquirer wins)
         self._round_gen: dict[int, object] = {}
+        # round -> (corpus key, bounds) of its first acquirer: a worker
+        # marked dead mid-round must not change the round's partition
+        # for the siblings that acquire it later
+        self._round_bounds: dict[int, tuple] = {}
         self._abort_exc: BaseException | None = None
         self._dead: set[int] = set()
 
@@ -183,6 +187,11 @@ class FairSharder:
         Never blocks when rounds are already ordered (sync path, or
         ``n == 1``) — the wait condition is satisfied on entry.
 
+        Every acquirer of one round over the same corpus gets the
+        partition the first acquirer got, even when a worker is marked
+        dead in between: its shard is then orphaned and recovered inside
+        the round, and only the next round leaves it out.
+
         The returned ``round_no`` is the sharder-global round this
         partition belongs to — the key the fault-tolerant gather and
         round-tagged :meth:`update` use, and stable even when the caller
@@ -221,10 +230,19 @@ class FairSharder:
                     # the caller re-acquires it at the agreed generation
                     self._issued[worker] -= 1
                     raise GenerationMismatch(r, agreed, generation)
-        # safe outside the lock: round r cannot commit (and move the
-        # EMA) until THIS worker reports it, which happens only after
-        # the caller scores the slice these bounds describe
-        return r, self.bounds(total_items, boundaries)
+        # round r cannot commit (and move the EMA) until THIS worker
+        # reports it, which happens only after the caller scores the
+        # slice these bounds describe; the first acquirer's bounds for
+        # this corpus stand for the whole round
+        bounds = self.bounds(total_items, boundaries)
+        key = (total_items, None if boundaries is None
+               else tuple(np.asarray(boundaries).tolist()))
+        with self._cv:
+            if r >= self._committed:
+                pinned = self._round_bounds.setdefault(r, (key, bounds))
+                if pinned[0] == key:
+                    bounds = pinned[1]
+        return r, list(bounds)
 
     def acquire_bounds(self, worker: int, total_items: int,
                        boundaries=None) -> list[tuple[int, int]]:
@@ -309,5 +327,6 @@ class FairSharder:
                         + (1 - self.alpha) * self.throughput[wk])
             del self._pending[self._committed]
             self._round_gen.pop(self._committed, None)
+            self._round_bounds.pop(self._committed, None)
             self._committed += 1
             self._cv.notify_all()

@@ -55,6 +55,7 @@ from typing import Callable, Sequence
 
 import numpy as np
 
+from repro.core import tracing
 from repro.core.fair_sharding import GenerationMismatch
 from repro.core.faults import SearchOutcome
 
@@ -81,7 +82,7 @@ class ServeTimeoutError(ServeError):
 
 class _Request:
     __slots__ = ("texts", "n", "future", "t_submit", "deadline",
-                 "abandoned")
+                 "abandoned", "rid")
 
     def __init__(self, texts: list[str], deadline_ms: float | None = None):
         self.texts = texts
@@ -96,6 +97,7 @@ class _Request:
         # its Future is already resolved (ServeTimeoutError), so the
         # dispatcher skips it entirely
         self.abandoned = False
+        self.rid = -1              # admission order, set when accepted
 
     def remaining_s(self, now: float) -> float | None:
         return None if self.deadline is None else self.deadline - now
@@ -201,6 +203,8 @@ class EvaluatorServeBackend:
             inner = self.driver.search_async(
                 q_emb, sized, load_chunk, topk, deadline_s=deadline_s,
                 generation=prepared.generation)
+            # joins the micro-batch's span to the driver round it ran as
+            tracing.annotate(round=self.driver.stats["round"])
         except BaseException:
             self._release(prepared)
             raise
@@ -469,6 +473,7 @@ class ServeFrontend:
                 raise ServeOverloadError(
                     f"queue full ({self._queue.maxsize} pending "
                     f"requests); retry with backoff") from None
+            req.rid = self.stats["accepted"]
             self.stats["accepted"] += 1
         return req
 
@@ -584,7 +589,8 @@ class ServeFrontend:
 
     def _loop(self) -> None:
         while True:
-            batch, reason, stop = self._collect()
+            with tracing.span("trove.serve.collect"):
+                batch, reason, stop = self._collect()
             if batch:
                 self._dispatch(batch, reason)
             if stop:
@@ -595,6 +601,7 @@ class ServeFrontend:
                 return
 
     def _dispatch(self, batch: list[_Request], reason: str) -> None:
+        t_dispatch = time.monotonic()
         texts = [t for req in batch for t in req.texts]
         n_real = len(texts)
         # pad the micro-batch to its power-of-two rung (demux below only
@@ -608,6 +615,7 @@ class ServeFrontend:
             rung *= 2
         texts = texts + [texts[0]] * (rung - n_real)
         with self._lock:
+            bid = self.stats["batches"]
             self.stats["batches"] += 1
             self.stats["queries"] += n_real
             self.stats[f"flush_{reason}"] += 1
@@ -626,29 +634,36 @@ class ServeFrontend:
         kwargs = ({"deadline_s": deadline_s}
                   if self._backend_deadline and deadline_s is not None
                   else {})
+        for req in batch:
+            tracing.record("trove.serve.queue", req.t_submit, t_dispatch,
+                           request=req.rid, batch=bid)
         begin = getattr(self.backend, "begin", None)
         try:
-            if begin is not None:
-                # pipelined: scoring ran inline; merge/demux complete on
-                # the backend's reduce thread while we collect the next
-                # micro-batch
-                fut = begin(texts, self.topk, **kwargs)
-                fut.add_done_callback(
-                    lambda f, b=batch: self._demux(b, f))
-            else:
-                run = getattr(self.backend, "run", self.backend)
-                out = run(texts, self.topk, **kwargs)
-                self._finish(batch, out)
+            with tracing.span("trove.serve.batch", batch=bid, n_real=n_real,
+                              rung=rung):
+                if begin is not None:
+                    # pipelined: scoring ran inline; merge/demux complete
+                    # on the backend's reduce thread while we collect the
+                    # next micro-batch
+                    fut = begin(texts, self.topk, **kwargs)
+                    fut.add_done_callback(
+                        lambda f, b=batch: self._demux(b, f, bid))
+                else:
+                    run = getattr(self.backend, "run", self.backend)
+                    out = run(texts, self.topk, **kwargs)
+                    with tracing.span("trove.serve.demux", batch=bid):
+                        self._finish(batch, out)
         except BaseException as exc:       # noqa: BLE001 — routed to futures
             self._fail(batch, exc)
 
-    def _demux(self, batch: list[_Request], fut: Future) -> None:
+    def _demux(self, batch: list[_Request], fut: Future, bid: int) -> None:
         try:
             out = fut.result()
         except BaseException as exc:       # noqa: BLE001 — routed to futures
             self._fail(batch, exc)
             return
-        self._finish(batch, out)
+        with tracing.span("trove.serve.demux", batch=bid):
+            self._finish(batch, out)
 
     def _finish(self, batch: list[_Request], out) -> None:
         ids, scores = out

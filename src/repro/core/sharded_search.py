@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import tracing
 from repro.core.fair_sharding import FairSharder
 from repro.core.faults import (FaultInjector, InjectedTransportDrop,
                                SearchOutcome)
@@ -358,17 +359,23 @@ class ShardedSearchDriver:
             return
         bounds = [(off, min(off + self.chunk_size, hi))
                   for off in range(lo, hi, self.chunk_size)]
+
+        def load(off: int, end: int):
+            with tracing.span("trove.search.load"):
+                return load_chunk(off, end)
+
         if not self.prefetch or len(bounds) <= 1:
             for off, end in bounds:
-                yield off, load_chunk(off, end)
+                yield off, load(off, end)
             return
         with ThreadPoolExecutor(max_workers=1,
                                 thread_name_prefix="chunk-prefetch") as ex:
-            fut = ex.submit(load_chunk, *bounds[0])
+            fut = ex.submit(load, *bounds[0])
             for i, (off, _) in enumerate(bounds):
-                embs = fut.result()
+                with tracing.span("trove.search.wait"):
+                    embs = fut.result()
                 if i + 1 < len(bounds):
-                    fut = ex.submit(load_chunk, *bounds[i + 1])
+                    fut = ex.submit(load, *bounds[i + 1])
                 yield off, embs
 
     # -- superchunk scan executor ---------------------------------------------
@@ -451,24 +458,26 @@ class ShardedSearchDriver:
             for si, (off, embs) in enumerate(buf):
                 offs[si] = off
                 nvs[si] = embs.shape[0]
-            if all(isinstance(e, np.ndarray) for _, e in buf):
-                host = np.zeros((s, c, dim), np.float32)
-                for si, (_, embs) in enumerate(buf):
-                    host[si, :embs.shape[0]] = embs
-                tile = jax.device_put(host, dev)
-            else:           # device-resident chunks (online encode path)
-                parts = []
-                for _, embs in buf:
-                    e = jnp.asarray(embs, jnp.float32)
-                    if e.shape[0] < c:
-                        e = jnp.pad(e, ((0, c - e.shape[0]), (0, 0)))
-                    parts.append(e)
-                parts += [jnp.zeros((c, dim), jnp.float32, device=dev)
-                          ] * (s - len(buf))
-                tile = jnp.stack(parts)
-            state_v, state_i = kops.superchunk_update(
-                state_v, state_i, qp, tile, offs, nvs, k=topk,
-                score=self.score_impl, merge=merge)
+            with tracing.span("trove.search.tile"):
+                if all(isinstance(e, np.ndarray) for _, e in buf):
+                    host = np.zeros((s, c, dim), np.float32)
+                    for si, (_, embs) in enumerate(buf):
+                        host[si, :embs.shape[0]] = embs
+                    tile = jax.device_put(host, dev)
+                else:       # device-resident chunks (online encode path)
+                    parts = []
+                    for _, embs in buf:
+                        e = jnp.asarray(embs, jnp.float32)
+                        if e.shape[0] < c:
+                            e = jnp.pad(e, ((0, c - e.shape[0]), (0, 0)))
+                        parts.append(e)
+                    parts += [jnp.zeros((c, dim), jnp.float32, device=dev)
+                              ] * (s - len(buf))
+                    tile = jnp.stack(parts)
+            with tracing.span("trove.search.scan"):
+                state_v, state_i = kops.superchunk_update(
+                    state_v, state_i, qp, tile, offs, nvs, k=topk,
+                    score=self.score_impl, merge=merge)
             dispatches += 1
 
         buf: list = []
@@ -561,10 +570,11 @@ class ShardedSearchDriver:
             bounds = self.sharder.bounds(int(n_docs), boundaries)
         lo, hi = bounds[self.worker_index]
         n_chunks = -(-max(hi - lo, 0) // self.chunk_size)
-        t0 = time.monotonic()
-        heap, dispatches, executor, s = self._score_range(
-            q_emb, lo, hi, load_chunk, topk, round_no)
-        seconds = time.monotonic() - t0
+        with tracing.span("trove.search.score", round=round_no):
+            t0 = time.monotonic()
+            heap, dispatches, executor, s = self._score_range(
+                q_emb, lo, hi, load_chunk, topk, round_no)
+            seconds = time.monotonic() - t0
         # Report the round.  A shared sharder (SimulatedCluster) hears
         # every worker directly; with per-process sharder replicas (real
         # multi-node) the transport must exchange observations or no
@@ -596,35 +606,38 @@ class ShardedSearchDriver:
         :class:`~repro.core.faults.SearchOutcome` carrying per-query
         coverage; barrier transports return the plain finalized tuple.
         """
-        if self.n_workers > 1 and self.gather is not None:
-            round_no = ctx["round_no"] if ctx is not None else None
-            resilient = getattr(self.gather, "merge_resilient", None)
-            if resilient is not None and ctx is not None:
-                dropped = False
-                if self.fault_injector is not None:
-                    try:
-                        self.fault_injector.on_gather(self.worker_index,
-                                                      round_no)
-                    except InjectedTransportDrop:
-                        # this worker's state is lost in flight; it
-                        # stays alive and joins the recovery instead
-                        dropped = True
-                vals, ids, coverage = resilient(
-                    heap, self.worker_index, round_no, ctx["bounds"],
-                    ctx["rescore"], dropped=dropped,
-                    round_deadline_s=self.round_deadline_s,
-                    max_retries=self.max_shard_retries,
-                    backoff_s=self.retry_backoff_s,
-                    deadline_s=ctx["deadline_s"])
-                return SearchOutcome(
-                    (vals, ids), coverage=coverage,
-                    degraded=bool((coverage < 1.0).any()))
-            if self.fault_injector is not None and round_no is not None:
-                # a drop against a barrier transport propagates: the
-                # legacy abort-the-round behavior
-                self.fault_injector.on_gather(self.worker_index, round_no)
-            heap = self.gather.merge(heap, self.worker_index)
-        return heap.finalize()
+        round_no = ctx["round_no"] if ctx is not None else None
+        with tracing.span("trove.search.reduce",
+                          round=-1 if round_no is None else round_no):
+            if self.n_workers > 1 and self.gather is not None:
+                resilient = getattr(self.gather, "merge_resilient", None)
+                if resilient is not None and ctx is not None:
+                    dropped = False
+                    if self.fault_injector is not None:
+                        try:
+                            self.fault_injector.on_gather(
+                                self.worker_index, round_no)
+                        except InjectedTransportDrop:
+                            # this worker's state is lost in flight; it
+                            # stays alive and joins the recovery instead
+                            dropped = True
+                    vals, ids, coverage = resilient(
+                        heap, self.worker_index, round_no, ctx["bounds"],
+                        ctx["rescore"], dropped=dropped,
+                        round_deadline_s=self.round_deadline_s,
+                        max_retries=self.max_shard_retries,
+                        backoff_s=self.retry_backoff_s,
+                        deadline_s=ctx["deadline_s"])
+                    return SearchOutcome(
+                        (vals, ids), coverage=coverage,
+                        degraded=bool((coverage < 1.0).any()))
+                if self.fault_injector is not None and round_no is not None:
+                    # a drop against a barrier transport propagates: the
+                    # legacy abort-the-round behavior
+                    self.fault_injector.on_gather(self.worker_index,
+                                                  round_no)
+                heap = self.gather.merge(heap, self.worker_index)
+            return heap.finalize()
 
     def search(self, q_emb, n_docs, load_chunk: ChunkLoader,
                topk: int, deadline_s: float | None = None,

@@ -259,6 +259,36 @@ def test_no_survivor_left_degrades_to_reporting_ranks(synth):
     np.testing.assert_allclose(outs[0].coverage, 0.5)
 
 
+def test_crash_before_the_sibling_acquires_keeps_the_round_partition(synth):
+    """Rank 1 acquires round 0 only once rank 0 has crashed and been
+    marked dead: it must still get round 0's partition (so rank 0's
+    shard is orphaned, its rescue crashes, coverage 0.5), not a
+    partition that already leaves the dead rank out."""
+    q, docs = synth
+    inj = FaultInjector([
+        Fault(kind="crash", worker=0, round=0, phase="load"),
+        Fault(kind="crash", round=0, phase="retry", repeat=True)])
+    cluster = SimulatedCluster(2, resilient=True)
+    drivers = [ShardedSearchDriver(
+        n_workers=2, worker_index=rank, sharder=cluster.sharder,
+        gather=cluster.gather, score_impl="numpy", chunk_size=16,
+        fault_injector=inj, round_deadline_s=30.0, max_shard_retries=0)
+        for rank in range(2)]
+
+    def work(rank):
+        if rank == 1:
+            t_end = time.monotonic() + 30.0
+            while (not cluster.health.is_dead(0)
+                   and time.monotonic() < t_end):
+                time.sleep(0.005)
+            assert cluster.health.is_dead(0)
+        return drivers[rank].search(q, N_DOCS, _load_from(docs), K)
+
+    outs = cluster.run(work)
+    assert outs[1].degraded
+    np.testing.assert_allclose(outs[1].coverage, 0.5)
+
+
 # -- FairSharder: diagnostics + dead-worker bookkeeping -----------------------
 
 
@@ -297,6 +327,17 @@ def test_abort_releases_waiters_with_diagnostics():
     assert "aborted while worker 0 waited for round 1" in str(err)
     assert "pending" in str(err)
     assert err.__cause__ is boom
+
+
+def test_death_mid_round_leaves_the_round_partition_alone():
+    s = FairSharder(2)
+    r0, b0 = s.acquire(0, 100)
+    s.mark_dead(0)
+    r1, b1 = s.acquire(1, 100)
+    assert r0 == r1 == 0 and b1 == b0 == [(0, 50), (50, 100)]
+    s.update(1, 50, 1.0, round_no=0)        # round 0 commits without 0
+    r, bounds = s.acquire(1, 100)
+    assert r == 1 and bounds == [(0, 0), (0, 100)]
 
 
 def test_mark_dead_zeroes_share_and_unblocks_round():
