@@ -49,8 +49,8 @@ from repro.core.encode_pipeline import EncodePipeline, PipelineChunkSource
 from repro.core.fair_sharding import FairSharder
 from repro.core.metrics import compute_metrics
 from repro.core.sharded_search import (  # noqa: F401 — re-exported API
-    SCORE_BACKENDS, MergeFnGather, ProcessAllGather, ShardedSearchDriver,
-    get_score_backend)
+    SCORE_BACKENDS, MergeFnGather, ProcessAllGather, ResidentRows,
+    ShardedSearchDriver, get_score_backend)
 from repro.data.table import stable_id_hash, stable_id_hash_array
 from repro.data.views import ConcatView, DatasetView, as_view
 
@@ -415,10 +415,11 @@ class RetrievalEvaluator:
 
         ``device_resident=True`` additionally materializes the corpus
         embeddings as one array living where scoring happens (device for
-        the device backends, host for ``numpy``): chunk loads become
-        zero-copy slices — no per-request mmap reads or encode. Encoding
-        (and cache warm-up) happens here, so construction is the
-        expensive pass.
+        the device backends, host for ``numpy``): no per-request mmap
+        reads or encode.  On the device it is a :class:`ResidentRows`,
+        which the driver's scan reads in place; on the host, chunk loads
+        are slices.  Encoding (and cache warm-up) happens here, so
+        construction is the expensive pass.
         """
         on_device = self.args.score_impl != "numpy"
         corpus_v = self._corpus_view(corpus)
@@ -434,9 +435,11 @@ class RetrievalEvaluator:
             embs = np.asarray(
                 self.encode_corpus(all_hashes, corpus_texts, cache),
                 np.float32)
-            arr = jax.device_put(embs, self.device) if on_device else embs
-            return PreparedCorpus(all_hashes, n_docs,
-                                  lambda lo, hi: arr[lo:hi])
+            if not on_device:
+                return PreparedCorpus(all_hashes, n_docs,
+                                      lambda lo, hi: embs[lo:hi])
+            return PreparedCorpus(all_hashes, n_docs, ResidentRows(
+                embs, self.args.encode_batch_size, self.device))
 
         # cached-corpus plan: when the cache already covers the corpus,
         # pin a snapshot and resolve the position->row mapping ONCE (or

@@ -12,7 +12,9 @@ entry point (``RetrievalEvaluator.search``, ``mine_hard_negatives``,
   * **stream**    — each worker pulls its slice in ``chunk_size`` chunks
     through a caller-supplied ``load_chunk(lo, hi)`` (cache read / encode
     / h2d) with **double-buffered async prefetch**: chunk ``i+1``'s load
-    overlaps chunk ``i``'s scoring on the worker's main thread;
+    overlaps chunk ``i``'s scoring on the worker's main thread; a corpus
+    already resident on the device (:class:`ResidentRows`) is instead
+    read in place inside the jitted scan, with no per-chunk load;
   * **score**     — a pluggable backend (``SCORE_BACKENDS``) folds each
     chunk into a local :class:`FastResultHeapq` (Q, k) state;
   * **reduce**    — per-worker states merge through a
@@ -236,8 +238,37 @@ def autotune_superchunk_size(n_queries: int, dim: int, chunk_size: int,
 
 # legacy pull contract: (lo, hi) -> embeddings.  Objects exposing
 # ``open_slice(lo, hi, chunk_size)`` (chunk sources, e.g. the bucketed
-# encode pipeline) are accepted wherever a ChunkLoader is.
+# encode pipeline) and :class:`ResidentRows` are accepted wherever a
+# ChunkLoader is.
 ChunkLoader = Callable[[int, int], "np.ndarray | jax.Array"]
+
+
+class ResidentRows:
+    """A corpus held whole on the device as one float32 row array.
+
+    ``rows`` is ``(n + pad, d)``: the ``n`` corpus rows, then one
+    lane-aligned chunk of zero rows (padded on the host, so the device
+    holds one copy) so a scan step's fixed-size read starting at any
+    chunk offset stays inside the array.  The driver detects the
+    ``rows`` device array and scans it in place (one ``dynamic_slice``
+    per step inside the jitted scan); calling the object keeps the
+    legacy ``(lo, hi) -> rows[lo:hi]`` contract for every other
+    consumer.
+    """
+
+    __slots__ = ("rows", "n")
+
+    def __init__(self, host_rows: np.ndarray, chunk_size: int,
+                 device=None):
+        from repro.kernels.ops import scan_chunk_rows
+        host_rows = np.asarray(host_rows, np.float32)
+        pad = np.zeros((scan_chunk_rows(chunk_size, interpret=False),
+                        host_rows.shape[1]), np.float32)
+        self.rows = jax.device_put(np.concatenate([host_rows, pad]), device)
+        self.n = host_rows.shape[0]
+
+    def __call__(self, lo: int, hi: int) -> jax.Array:
+        return self.rows[lo:hi]
 
 
 class ShardedSearchDriver:
@@ -264,7 +295,10 @@ class ShardedSearchDriver:
         per-chunk.  Never changes results — the scan replays the exact
         per-chunk merge sequence on device.
     superchunk_max_mb : cap on the stacked (S, C, d) tile so autotuned
-        or configured S can't blow device memory.
+        or configured S can't blow device memory.  A device-resident
+        corpus (a loader with a ``rows`` device array, e.g.
+        :class:`ResidentRows`) is scanned in place with no tile, so the
+        cap does not bound its S.
     fault_injector : optional :class:`repro.core.faults.FaultInjector`
         consulted at the chunk-load and gather fault points (chaos
         tests, ``serve --chaos``).  ``None`` = no injection.
@@ -380,20 +414,23 @@ class ShardedSearchDriver:
 
     # -- superchunk scan executor ---------------------------------------------
     def _resolve_superchunk_size(self, n_queries: int, dim: int,
-                                 k: int) -> int:
-        """Effective S for this search (config / autotune / memory cap)."""
+                                 k: int, *, tiled: bool = True) -> int:
+        """Effective S for this search (config / autotune / memory cap).
+        ``tiled=False`` (the resident scan, which uploads no tile) skips
+        the memory cap."""
         if self.superchunk_size == 1:
             return 1
         merge = "pallas" if self.heap_impl == "pallas" else "jax"
         s = (self.superchunk_size if self.superchunk_size > 1 else
              autotune_superchunk_size(n_queries, dim, self.chunk_size, k,
                                       self.score_impl, merge))
+        if not tiled:
+            return s
         # budget what actually uploads: compiled backends lane-align the
         # chunk axis to 128 (see superchunk_update), so a chunk_size=32
         # tile occupies 4x its nominal bytes on device
-        from repro.kernels.ops import _default_interpret
-        c = (self.chunk_size if _default_interpret()
-             else self.chunk_size + (-self.chunk_size) % 128)
+        from repro.kernels.ops import scan_chunk_rows
+        c = scan_chunk_rows(self.chunk_size)
         tile_bytes = max(1, c * max(dim, 1) * 4)
         cap = max(1, (self.superchunk_max_mb << 20) // tile_bytes)
         return max(1, min(s, cap))
@@ -422,6 +459,25 @@ class ShardedSearchDriver:
                     close()
         return faulty()
 
+    @staticmethod
+    def _scan_state(q_emb, topk: int):
+        """The scan executors' inputs: the queries padded to a multiple
+        of 8 rows and an empty (Q, k) state, on the queries' device (the
+        worker's chip; host queries: the default device), plus that
+        device."""
+        n_q = q_emb.shape[0]
+        dev = (next(iter(q_emb.devices())) if isinstance(q_emb, jax.Array)
+               else None)
+        pad_rows = (-n_q) % 8
+        qp = jax.device_put(q_emb, dev)
+        if pad_rows:
+            qp = jnp.pad(qp, ((0, pad_rows), (0, 0)))
+        state_v = jnp.full((n_q + pad_rows, topk), -jnp.inf, jnp.float32,
+                           device=dev)
+        state_i = jnp.full((n_q + pad_rows, topk), -1, jnp.int32,
+                           device=dev)
+        return qp, state_v, state_i, dev
+
     def _search_superchunk(self, q_emb, heap: FastResultHeapq, chunks,
                            topk: int, s: int) -> int:
         """Stream the slice through one-dispatch-per-superchunk scans.
@@ -437,18 +493,7 @@ class ShardedSearchDriver:
         n_q, dim = q_emb.shape
         c = self.chunk_size
         merge = "pallas" if self.heap_impl == "pallas" else "jax"
-        # uploaded tiles and the (Q, k) state follow the query embeddings
-        # onto their device (the worker's chip); host queries: default
-        dev = (next(iter(q_emb.devices())) if isinstance(q_emb, jax.Array)
-               else None)
-        pad_rows = (-n_q) % 8
-        qp = jax.device_put(q_emb, dev)
-        if pad_rows:
-            qp = jnp.pad(qp, ((0, pad_rows), (0, 0)))
-        state_v = jnp.full((n_q + pad_rows, topk), -jnp.inf, jnp.float32,
-                           device=dev)
-        state_i = jnp.full((n_q + pad_rows, topk), -1, jnp.int32,
-                           device=dev)
+        qp, state_v, state_i, dev = self._scan_state(q_emb, topk)
         dispatches = 0
 
         def flush(buf):
@@ -491,6 +536,59 @@ class ShardedSearchDriver:
         heap.adopt_state(state_v[:n_q], state_i[:n_q])
         return dispatches
 
+    def _resident_rows(self, load_chunk: ChunkLoader, lo: int, hi: int):
+        """The device row array to scan in place for ``[lo, hi)``, or
+        ``None`` to stream.  The loader qualifies when its ``rows`` is a
+        device array long enough for the last chunk's fixed-size scan
+        read (:class:`ResidentRows` pads for exactly that)."""
+        rows = getattr(load_chunk, "rows", None)
+        if not isinstance(rows, jax.Array) or rows.ndim != 2:
+            return None
+        from repro.kernels.ops import scan_chunk_rows
+        last = lo + (hi - lo - 1) // self.chunk_size * self.chunk_size
+        if last + scan_chunk_rows(self.chunk_size) > rows.shape[0]:
+            return None
+        return rows
+
+    def _search_resident(self, q_emb, heap: FastResultHeapq, rows,
+                         lo: int, hi: int, topk: int, s: int,
+                         round_no: int, phase: str) -> int:
+        """Scan ``[lo, hi)`` of a device-resident corpus in place.
+
+        Chunk boundaries are ``range(lo, hi, chunk_size)``, grouped S to
+        a dispatch exactly as the streamed executor groups them; each
+        dispatch hands only the group's (S,) offsets and valid counts to
+        ``kernels.ops.superchunk_update``, whose resident scan reads
+        every chunk out of ``rows`` on the device.  Nothing is sliced,
+        padded or stacked per chunk.  The chunk-level fault point fires
+        once per chunk, in order, before the dispatch that scans it.
+        Returns the number of scan dispatches.
+        """
+        from repro.kernels import ops as kops
+        n_q = q_emb.shape[0]
+        c = self.chunk_size
+        merge = "pallas" if self.heap_impl == "pallas" else "jax"
+        qp, state_v, state_i, _ = self._scan_state(q_emb, topk)
+        starts = np.arange(lo, hi, c, dtype=np.int64)
+        dispatches = 0
+        for g in range(0, len(starts), s):
+            group = starts[g:g + s]
+            offs = np.zeros(s, np.int32)
+            nvs = np.zeros(s, np.int32)
+            offs[:len(group)] = group
+            nvs[:len(group)] = np.minimum(group + c, hi) - group
+            if self.fault_injector is not None:
+                for ci in range(g, g + len(group)):
+                    self.fault_injector.on_chunk(self.worker_index,
+                                                 round_no, ci, phase)
+            with tracing.span("trove.search.scan"):
+                state_v, state_i = kops.superchunk_update(
+                    state_v, state_i, qp, rows, offs, nvs, k=topk,
+                    score=self.score_impl, merge=merge, chunk_size=c)
+            dispatches += 1
+        heap.adopt_state(state_v[:n_q], state_i[:n_q])
+        return dispatches
+
     def _score_range(self, q_emb, lo: int, hi: int,
                      load_chunk: ChunkLoader, topk: int, round_no: int,
                      phase: str = "load"):
@@ -507,8 +605,15 @@ class ShardedSearchDriver:
         heap = FastResultHeapq(n_queries, topk, impl=self.heap_impl)
         scan_ok = (self.score_impl in ("jax", "pallas_fused")
                    and self.heap_impl in ("jax", "pallas") and hi > lo)
-        s = (self._resolve_superchunk_size(n_queries, q_emb.shape[1], topk)
+        rows = self._resident_rows(load_chunk, lo, hi) if scan_ok else None
+        s = (self._resolve_superchunk_size(n_queries, q_emb.shape[1], topk,
+                                           tiled=rows is None)
              if scan_ok else 1)
+        if rows is not None and s > 1:
+            executor = "resident"
+            dispatches = self._search_resident(q_emb, heap, rows, lo, hi,
+                                               topk, s, round_no, phase)
+            return heap, dispatches, executor, s
         chunks = self._chunk_iter(lo, hi, load_chunk, round_no, phase)
         if scan_ok and s > 1:
             executor = "superchunk"

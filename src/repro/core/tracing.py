@@ -27,6 +27,8 @@ Span names, from the request down (ids in brackets):
   trove.search.load                    one chunk load (prefetch thread)
   trove.search.wait                    scorer waiting for that load
   trove.search.tile / .scan            superchunk tile build / scan call
+                                       (a device-resident corpus: .scan
+                                       only, no load, wait or tile)
   trove.search.reduce [round]          merge + finalize (reduce thread)
   trove.ivf.select / .gather           IVF list selection / row fetch
   trove.encode.tokenize [n]            host tokenization

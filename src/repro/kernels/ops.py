@@ -110,6 +110,55 @@ def fused_score_topk(queries, docs, k: int, *, id_offset=0,
 # and no host materialization until finalize().  This is what collapses
 # the per-chunk Python + jit-dispatch storm (ShardedSearchDriver pays one
 # dispatch per superchunk instead of one per encode_batch_size chunk).
+# The resident variant reads each step's chunk straight out of a corpus
+# array that already lives on the device (``dynamic_slice`` at the traced
+# offset) instead of from a stacked tile, so a corpus held on the chip is
+# scanned in place: no per-chunk slice, pad or stack before the dispatch.
+# Both variants fold every chunk through the same ``_fold_chunk`` body.
+
+
+def scan_chunk_rows(chunk_size: int, interpret: bool | None = None) -> int:
+    """Rows one scan step reads: the chunk, lane-aligned to 128 rows for
+    Mosaic (interpret mode has no alignment constraint).  Rows past the
+    chunk's ``n_valid`` are masked."""
+    interpret = _default_interpret() if interpret is None else interpret
+    return chunk_size if interpret else chunk_size + (-chunk_size) % 128
+
+
+def _fold_chunk(v, i, queries, docs, off, nv, k, score, merge, interpret):
+    """One scan step: score the (C, d) chunk ``docs`` at global offset
+    ``off`` (rows at or past ``nv`` masked) and merge it into the (Q, k)
+    state ``(v, i)``."""
+    c = docs.shape[0]
+    if score == "pallas_fused":
+        # in-kernel score+top-k: each chunk arrives pre-reduced to
+        # (Q, k); merge exactly like FastResultHeapq.merge_arrays
+        cand_v, cand_i = _topk.fused_score_topk_pallas(
+            queries, docs, k, id_offset=off, n_valid=nv,
+            bq=128, bn=min(512, max(c, 8)), interpret=interpret)
+        cand_v = jnp.where(jnp.isnan(cand_v), _topk.NEG_INF, cand_v)
+        cv = jnp.concatenate([v, cand_v], axis=1)
+        ci = jnp.concatenate([i, cand_i], axis=1)
+        top_v, pos = jax.lax.top_k(cv, k)
+        return top_v, jnp.take_along_axis(ci, pos, axis=1)
+    # score == "jax": device matmul, then the heap-impl merge
+    scores = jax.lax.dot_general(
+        queries, docs, dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)                 # (Q, C)
+    iota = jnp.arange(c, dtype=jnp.int32)
+    valid = iota < nv
+    scores = jnp.where(valid[None, :], scores, _topk.NEG_INF)
+    scores = jnp.where(jnp.isnan(scores), _topk.NEG_INF, scores)
+    cids = jnp.where(valid, iota + off, -1)
+    if merge == "pallas":
+        return _topk.topk_update_pallas(
+            v, i, scores, cids, bq=min(128, v.shape[0]),
+            bc=min(512, c), interpret=interpret)
+    cv = jnp.concatenate([v, scores], axis=1)
+    ci = jnp.concatenate(
+        [i, jnp.broadcast_to(cids[None, :], scores.shape)], axis=1)
+    top_v, pos = jax.lax.top_k(cv, k)
+    return top_v, jnp.take_along_axis(ci, pos, axis=1)
 
 
 @functools.partial(jax.jit,
@@ -117,51 +166,50 @@ def fused_score_topk(queries, docs, k: int, *, id_offset=0,
                    donate_argnums=(0, 1))
 def _superchunk_scan_jit(vals, ids, queries, tile, offsets, n_valids, k,
                          score, merge, interpret):
-    c = tile.shape[1]
-
     def step(carry, xs):
-        v, i = carry
         docs, off, nv = xs
-        if score == "pallas_fused":
-            # in-kernel score+top-k: each chunk arrives pre-reduced to
-            # (Q, k); merge exactly like FastResultHeapq.merge_arrays
-            cand_v, cand_i = _topk.fused_score_topk_pallas(
-                queries, docs, k, id_offset=off, n_valid=nv,
-                bq=128, bn=min(512, max(c, 8)), interpret=interpret)
-            cand_v = jnp.where(jnp.isnan(cand_v), _topk.NEG_INF, cand_v)
-            cv = jnp.concatenate([v, cand_v], axis=1)
-            ci = jnp.concatenate([i, cand_i], axis=1)
-            top_v, pos = jax.lax.top_k(cv, k)
-            return (top_v, jnp.take_along_axis(ci, pos, axis=1)), None
-        # score == "jax": device matmul, then the heap-impl merge
-        scores = jax.lax.dot_general(
-            queries, docs, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)             # (Q, C)
-        iota = jnp.arange(c, dtype=jnp.int32)
-        valid = iota < nv
-        scores = jnp.where(valid[None, :], scores, _topk.NEG_INF)
-        scores = jnp.where(jnp.isnan(scores), _topk.NEG_INF, scores)
-        cids = jnp.where(valid, iota + off, -1)
-        if merge == "pallas":
-            v, i = _topk.topk_update_pallas(
-                v, i, scores, cids, bq=min(128, v.shape[0]),
-                bc=min(512, c), interpret=interpret)
-            return (v, i), None
-        cv = jnp.concatenate([v, scores], axis=1)
-        ci = jnp.concatenate(
-            [i, jnp.broadcast_to(cids[None, :], scores.shape)], axis=1)
-        top_v, pos = jax.lax.top_k(cv, k)
-        return (top_v, jnp.take_along_axis(ci, pos, axis=1)), None
+        return _fold_chunk(*carry, queries, docs, off, nv, k, score,
+                           merge, interpret), None
 
     (vals, ids), _ = jax.lax.scan(
         step, (vals, ids), (tile, offsets, n_valids))
     return vals, ids
 
 
+@functools.partial(jax.jit,
+                   static_argnames=("c", "k", "score", "merge",
+                                    "interpret"),
+                   donate_argnums=(0, 1))
+def _superchunk_scan_resident_jit(vals, ids, queries, rows, offsets,
+                                  n_valids, c, k, score, merge, interpret):
+    def step(carry, xs):
+        off, nv = xs
+        # rows past n_valid read as zero, as in a streamed tile.  The
+        # multiply also pins the dot's operand rounding to this chunk: a
+        # bare slice (or select) lets XLA move that convert ahead of the
+        # slice and out of the loop, converting the whole corpus on every
+        # dispatch (tests/test_tpu_compile.py checks it stays out)
+        keep = (jnp.arange(c) < nv).astype(rows.dtype)[:, None]
+        docs = jax.lax.dynamic_slice(rows, (off, 0),
+                                     (c, rows.shape[1])) * keep
+        return _fold_chunk(*carry, queries, docs, off, nv, k, score,
+                           merge, interpret), None
+
+    (vals, ids), _ = jax.lax.scan(step, (vals, ids), (offsets, n_valids))
+    return vals, ids
+
+
 def superchunk_update(vals, ids, queries, tile, offsets, n_valids, *,
                       k: int, score: str = "jax", merge: str = "jax",
-                      interpret: bool | None = None):
-    """Fold an (S, C, d) superchunk into the (Q, k) state in ONE dispatch.
+                      interpret: bool | None = None,
+                      chunk_size: int | None = None):
+    """Fold S corpus chunks into the (Q, k) state in ONE dispatch.
+
+    ``tile`` is an (S, C, d) superchunk, step ``s`` scoring ``tile[s]``;
+    or, with ``chunk_size``, the device-resident float32 corpus (N, d),
+    step ``s`` reading ``scan_chunk_rows(chunk_size)`` rows in place at
+    ``offsets[s]`` (only read, never copied whole).  Both forms run the
+    same step body, so the same chunks give the same state bit for bit.
 
     ``vals``/``ids`` are DONATED — callers must hold onto the returned
     state instead.  ``offsets``/``n_valids`` are per-step (S,) int32:
@@ -170,16 +218,31 @@ def superchunk_update(vals, ids, queries, tile, offsets, n_valids, *,
     ``score`` selects matmul vs in-kernel fused scoring, ``merge``
     selects the jnp vs pallas top-k merge — mirroring the per-chunk
     backends bit for bit.
+
+    A resident read must end inside the corpus (an out-of-range
+    ``dynamic_slice`` would clamp its start and score the wrong rows):
+    its owner pads it by ``scan_chunk_rows(chunk_size)`` rows, and an
+    offset whose read would run past the end raises here.
     """
     interpret = _default_interpret() if interpret is None else interpret
     assert queries.shape[0] == vals.shape[0], (queries.shape, vals.shape)
+    queries = jnp.asarray(queries, jnp.float32)
+    n_valids = jnp.asarray(n_valids, jnp.int32)
+    if chunk_size is not None:
+        c = scan_chunk_rows(chunk_size, interpret)
+        offsets = np.asarray(offsets, np.int32)
+        if offsets.size and int(offsets.max()) + c > tile.shape[0]:
+            raise ValueError(
+                f"a {c}-row read at offset {int(offsets.max())} runs past "
+                f"the {tile.shape[0]} resident rows")
+        return _superchunk_scan_resident_jit(
+            vals, ids, queries, tile, jnp.asarray(offsets), n_valids, c, k,
+            score, merge, interpret)
     tile = jnp.asarray(tile, jnp.float32)
     if not interpret:
-        # lane-align the chunk axis for Mosaic; padded rows are masked by
-        # n_valid (interpret mode skips this — no alignment constraint)
+        # lane-align the chunk axis for Mosaic (scan_chunk_rows); padded
+        # rows are masked by n_valid
         tile = _pad_axis(tile, 1, 128, 0.0)
     return _superchunk_scan_jit(
-        vals, ids, jnp.asarray(queries, jnp.float32), tile,
-        jnp.asarray(offsets, jnp.int32), jnp.asarray(n_valids, jnp.int32),
-        k, score, merge, interpret)
-
+        vals, ids, queries, tile, jnp.asarray(offsets, jnp.int32),
+        n_valids, k, score, merge, interpret)

@@ -23,7 +23,7 @@ from repro.core.faults import (Fault, FaultInjector, InjectedCrash,
                                InjectedTransportDrop, SearchOutcome,
                                WorkerHealth, full_coverage)
 from repro.core.serving import ServeFrontend, ServeTimeoutError
-from repro.core.sharded_search import ShardedSearchDriver
+from repro.core.sharded_search import ResidentRows, ShardedSearchDriver
 from repro.launch.distributed import SimulatedCluster
 from repro.training.fault_tolerance import resilient_loop
 
@@ -287,6 +287,95 @@ def test_crash_before_the_sibling_acquires_keeps_the_round_partition(synth):
     outs = cluster.run(work)
     assert outs[1].degraded
     np.testing.assert_allclose(outs[1].coverage, 0.5)
+
+
+# -- the resident executor's chunk fault point --------------------------------
+
+
+class _NoLoadRows(ResidentRows):
+    """A device-resident corpus whose ``(lo, hi)`` loader must never run."""
+
+    __slots__ = ()
+
+    def __call__(self, lo, hi):
+        raise AssertionError(f"resident corpus loaded as chunk [{lo}, {hi})")
+
+
+class _RecordingInjector(FaultInjector):
+    def __init__(self, faults, events):
+        super().__init__(faults)
+        self.events = events
+
+    def on_chunk(self, worker, round_no, chunk_index, phase="load"):
+        self.events.append(("chunk", chunk_index))
+        super().on_chunk(worker, round_no, chunk_index, phase)
+
+
+def test_resident_chunk_fault_point_fires_per_chunk_before_its_scan(
+        synth, monkeypatch):
+    """200 rows in chunks of 16, S=4: chunks 0..12 each pass the fault
+    point once, in order, and every group of four passes it before the
+    in-place scan that reads it is dispatched."""
+    from repro.kernels import ops
+    q, docs = synth
+    events: list = []
+    scan = ops.superchunk_update
+
+    def recording_scan(*args, **kw):
+        events.append(("scan", [int(o) for o in args[4]]))
+        return scan(*args, **kw)
+
+    monkeypatch.setattr(ops, "superchunk_update", recording_scan)
+    driver = ShardedSearchDriver(score_impl="jax", chunk_size=16,
+                                 superchunk_size=4,
+                                 fault_injector=_RecordingInjector([],
+                                                                   events))
+    _, pos = driver.search(q, N_DOCS, _NoLoadRows(docs, 16), K)
+    assert driver.stats["executor"] == "resident"
+    expected = []
+    for g in range(0, 13, 4):
+        group = list(range(g, min(g + 4, 13)))
+        expected += [("chunk", ci) for ci in group]
+        expected.append(("scan", [16 * ci for ci in group]
+                         + [0] * (4 - len(group))))
+    assert events == expected
+    np.testing.assert_array_equal(pos, _oracle(q, docs, N_DOCS)[1])
+
+
+@pytest.mark.parametrize("w", (2, 3))
+def test_resident_recovery_is_bitwise_equal_to_no_fault_round(synth, w):
+    """A worker crashes after its first in-place scan (chunk 4 of its
+    shard): a survivor rescans the orphaned shard through the same
+    resident path and every rank's merged round equals the no-fault
+    round bit for bit, scores included, with full coverage."""
+    q, docs = synth
+
+    def cluster_round(injector):
+        cluster = SimulatedCluster(w, resilient=True)
+        drivers = [ShardedSearchDriver(
+            n_workers=w, worker_index=rank, sharder=cluster.sharder,
+            gather=cluster.gather, score_impl="jax", chunk_size=16,
+            superchunk_size=4, fault_injector=injector,
+            round_deadline_s=0.15, retry_backoff_s=0.01)
+            for rank in range(w)]
+        src = _NoLoadRows(docs, 16)
+        outs = cluster.run(lambda rank: drivers[rank].search(q, N_DOCS,
+                                                             src, K))
+        return outs, drivers
+
+    clean, _ = cluster_round(FaultInjector([]))
+    inj = FaultInjector([Fault(kind="crash", worker=1, round=0,
+                               phase="load", chunk=4)])
+    outs, drivers = cluster_round(inj)
+    assert inj.fired == [("crash", 1, 0, "load")]
+    assert drivers[0].stats["executor"] == "resident"
+    ref_pos = _oracle(q, docs, N_DOCS)[1]
+    for out, ref in zip(outs, clean):
+        np.testing.assert_array_equal(out[1], ref[1])
+        np.testing.assert_array_equal(out[0], ref[0])
+        np.testing.assert_array_equal(out[1], ref_pos)
+        np.testing.assert_array_equal(out.coverage, full_coverage(N_Q))
+        assert not out.degraded
 
 
 # -- FairSharder: diagnostics + dead-worker bookkeeping -----------------------

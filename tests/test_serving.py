@@ -378,6 +378,31 @@ def test_mixed_size_requests_match_solo_search(serve_env):
         fe.close()
 
 
+@pytest.mark.parametrize("score_impl", ("jax", "pallas_fused"))
+def test_device_resident_corpus_serves_like_the_streamed_one(serve_env,
+                                                             score_impl):
+    """A frontend over the device-resident corpus (the default) scans it
+    in place, and answers exactly as one streaming the same cached rows
+    (``device_resident=False``): ids and scores bitwise."""
+    texts = list(serve_env["queries"].values())[:7]
+    out, executor = {}, {}
+    for resident in (True, False):
+        fe = ServeFrontend.from_evaluator(
+            serve_env["make"](score_impl), serve_env["corpus"],
+            serve_env["cache"], device_resident=resident)
+        try:
+            out[resident] = fe.search(texts, timeout=120)
+            executor[resident] = fe.backend.driver.stats["executor"]
+        finally:
+            fe.close()
+    assert executor == {True: "resident", False: "superchunk"}
+    np.testing.assert_array_equal(out[True][0], out[False][0])
+    np.testing.assert_array_equal(out[True][1], out[False][1])
+    for j, qid in enumerate(list(serve_env["queries"])[:7]):
+        np.testing.assert_array_equal(out[True][0][j],
+                                      serve_env["solo"][qid][0])
+
+
 def test_from_evaluator_defaults_come_from_args(serve_env):
     ev = serve_env["make"]("numpy")
     fe = ServeFrontend.from_evaluator(ev, serve_env["corpus"],

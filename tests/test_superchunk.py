@@ -5,7 +5,9 @@ The scan path (``kernels.ops.superchunk_update`` driven by
 dispatch path bit for bit for every device score_impl × heap_impl combo,
 across ragged tails, padded final superchunks, empty shards, and the
 prefetch pipeline — while collapsing the dispatch count to
-ceil(chunks / S).
+ceil(chunks / S).  The resident executor (a ``ResidentRows`` corpus read
+in place by ``kernels.ops.superchunk_update``) must return the
+streamed executor's state bit for bit, without ever loading a chunk.
 """
 
 import numpy as np
@@ -13,7 +15,7 @@ import pytest
 
 import jax.numpy as jnp
 
-from repro.core.sharded_search import (ShardedSearchDriver,
+from repro.core.sharded_search import (ResidentRows, ShardedSearchDriver,
                                        autotune_superchunk_size)
 from repro.kernels import ops
 
@@ -33,6 +35,40 @@ def _oracle(q, docs, k):
     full = q @ docs.T
     pos = np.argsort(-full, axis=1, kind="stable")[:, :k]
     return np.take_along_axis(full, pos, 1), pos
+
+
+class _NoLoadRows(ResidentRows):
+    """A resident corpus whose legacy ``(lo, hi)`` loader must never run:
+    the resident executor reads ``rows`` in place."""
+
+    __slots__ = ()
+
+    def __call__(self, lo, hi):
+        raise AssertionError(f"resident corpus loaded as chunk [{lo}, {hi})")
+
+
+class _BareRows:
+    """A loader whose ``rows`` device array has no padding past the end."""
+
+    def __init__(self, docs):
+        self.rows = jnp.asarray(docs)
+
+    def __call__(self, lo, hi):
+        return self.rows[lo:hi]
+
+
+def _cluster_search(q, n, load, w, k, **kw):
+    """Per-rank outs and driver stats of one W-worker round."""
+    from repro.launch.distributed import SimulatedCluster
+    if w == 1:
+        d = ShardedSearchDriver(**kw)
+        return [d.search(q, n, load, k)], [d.stats]
+    cluster = SimulatedCluster(w)
+    drivers = [ShardedSearchDriver(
+        n_workers=w, worker_index=rank, sharder=cluster.sharder,
+        gather=cluster.gather, **kw) for rank in range(w)]
+    outs = cluster.run(lambda rank: drivers[rank].search(q, n, load, k))
+    return outs, [d.stats for d in drivers]
 
 
 @pytest.mark.parametrize("heap_impl", SCAN_HEAP_IMPLS)
@@ -69,6 +105,87 @@ def test_scan_bitwise_equals_per_chunk(synth, score_impl, heap_impl):
     np.testing.assert_array_equal(outs[1][1], outs[4][1])
     np.testing.assert_allclose(outs[1][0], outs[4][0], rtol=1e-5,
                                atol=1e-6)
+
+
+@pytest.mark.parametrize("w", (1, 2, 3))
+@pytest.mark.parametrize("heap_impl", SCAN_HEAP_IMPLS)
+@pytest.mark.parametrize("score_impl", SCAN_SCORE_IMPLS)
+def test_resident_scan_bitwise_equals_streamed_and_oracle(
+        synth, score_impl, heap_impl, w):
+    """230 rows in chunks of 37 leave a ragged tail, S=3 a padded final
+    group, and W=2/3 shard bounds off the chunk grid: every rank's
+    resident round equals the streamed round bit for bit (scores too:
+    the same step body sees the same rows) and the oracle's ranking."""
+    q, docs = synth
+    kw = dict(score_impl=score_impl, heap_impl=heap_impl, chunk_size=37,
+              superchunk_size=3)
+    streamed, s_stats = _cluster_search(q, docs.shape[0],
+                                        lambda lo, hi: docs[lo:hi], w, 10,
+                                        **kw)
+    resident, r_stats = _cluster_search(q, docs.shape[0],
+                                        _NoLoadRows(docs, 37), w, 10, **kw)
+    _, ref_pos = _oracle(q, docs, 10)
+    assert {st["executor"] for st in s_stats} == {"superchunk"}
+    assert {st["executor"] for st in r_stats} == {"resident"}
+    if w > 1:
+        assert any(st["lo"] % 37 for st in r_stats)   # off the chunk grid
+    for (s_vals, s_pos), (r_vals, r_pos) in zip(streamed, resident):
+        np.testing.assert_array_equal(r_pos, s_pos)
+        np.testing.assert_array_equal(r_vals, s_vals)
+        np.testing.assert_array_equal(r_pos, ref_pos)
+
+
+@pytest.mark.parametrize("s", (3, 4, 8))
+def test_resident_dispatch_counts(synth, s):
+    """ceil(230/32) = 8 chunks fold into ceil(8/S) in-place scans, with
+    the round's counters those of a streamed round."""
+    q, docs = synth
+    d = ShardedSearchDriver(score_impl="jax", chunk_size=32,
+                            superchunk_size=s)
+    d.search(q, docs.shape[0], _NoLoadRows(docs, 32), 5)
+    assert d.stats["executor"] == "resident"
+    assert d.stats["chunks"] == 8
+    assert d.stats["dispatch_rounds"] == -(-8 // s)
+    assert d.stats["superchunk_size"] == s
+    assert (d.stats["lo"], d.stats["hi"], d.stats["items"]) == (0, 230, 230)
+
+
+def test_resident_rounds_share_one_executable(synth):
+    """Chunk offsets ride the scan xs: rounds over different ``[lo, hi)``
+    (moving shard bounds, unaligned to the chunk grid) reuse the resident
+    scan's compiled executable."""
+    q, docs = synth
+    src = _NoLoadRows(docs, 32)
+    d = ShardedSearchDriver(score_impl="jax", chunk_size=32,
+                            superchunk_size=4)
+    d._score_range(q, 0, 230, src, 5, 0)
+    before = ops._superchunk_scan_resident_jit._cache_size()
+    for round_no, (lo, hi) in enumerate(((7, 199), (101, 230), (0, 33))):
+        heap, _, executor, _ = d._score_range(q, lo, hi, src, 5,
+                                              round_no + 1)
+        assert executor == "resident"
+        _, ref_pos = _oracle(q, docs[lo:hi], 5)
+        np.testing.assert_array_equal(heap.finalize()[1], ref_pos + lo)
+    assert ops._superchunk_scan_resident_jit._cache_size() == before
+
+
+def test_resident_needs_room_for_the_last_read(synth):
+    """A device array without the padding a fixed-size read needs past
+    the last chunk start is streamed, never read out of bounds (a
+    clamped dynamic_slice would score the wrong rows)."""
+    q, docs = synth
+    bare = _BareRows(docs)
+    d = ShardedSearchDriver(score_impl="jax", chunk_size=32,
+                            superchunk_size=4)
+    _, pos = d.search(q, docs.shape[0], bare, 5)
+    assert d.stats["executor"] == "superchunk"
+    np.testing.assert_array_equal(pos, _oracle(q, docs, 5)[1])
+    with pytest.raises(ValueError, match="runs past"):
+        ops.superchunk_update(
+            jnp.full((16, 5), -jnp.inf), jnp.full((16, 5), -1, jnp.int32),
+            np.zeros((16, 16), np.float32), bare.rows,
+            np.array([224], np.int32), np.array([6], np.int32), k=5,
+            chunk_size=32)
 
 
 def test_scan_dispatch_counts(synth):

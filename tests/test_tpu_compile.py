@@ -86,6 +86,26 @@ def test_superchunk_scan_compiles(spec, score, merge):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("score,merge",
+                         [("pallas_fused", "jax"), ("pallas_fused", "pallas"),
+                          ("jax", "pallas"), ("jax", "jax")])
+def test_superchunk_scan_resident_compiles(spec, score, merge):
+    """The in-place scan of a 2^20-row corpus padded by one lane-aligned
+    chunk, at the serving shape: 256 chunks of 256 rows a dispatch.  Its
+    scratch stays far below the corpus: the dot's bf16 operand rounding
+    happens per chunk inside the loop, never as a whole-corpus convert
+    hoisted out of it (1.6 GB of scratch and a full pass per dispatch)."""
+    q, k, s, c = 32, 10, 256, 256
+    compiled = ops._superchunk_scan_resident_jit.lower(
+        spec((q, k)), spec((q, k), jnp.int32), spec((q, D)),
+        spec((2 ** 20 + c, D)), spec((s,), jnp.int32),
+        spec((s,), jnp.int32), c=c, k=k, score=score, merge=merge,
+        interpret=False).compile()
+    assert ("tpu_custom_call" in compiled.as_text()) == (
+        score == "pallas_fused" or merge == "pallas")
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
 def test_trove_base_encode_compiles(spec, topo):
     from repro.configs import get_arch
     from repro.models.encoder import DefaultEncoder
