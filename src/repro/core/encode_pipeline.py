@@ -15,8 +15,8 @@ The online-regime encoder wall has three parts, each addressed here:
     tokenizes up to ``encode_pipeline_depth`` windows ahead of the
     device encode stage (bounded queue), so host tokenization overlaps
     device compute; each call runs :meth:`HashTokenizer.
-    batch_encode_ids` (unique-token ``np.unique`` path) fanned over a
-    ``tokenizer_workers`` pool — the fan-out parallelizes GIL-releasing
+    batch_encode_ids` (each distinct token hashed at most once) fanned
+    over a ``tokenizer_workers`` pool — the fan-out parallelizes GIL-releasing
     tokenizers (e.g. duck-typed Rust HF tokenizers); for the
     pure-Python GIL-bound HashTokenizer the overlap is the win.
   * **host round-trips** — the jitted encode step donates its token

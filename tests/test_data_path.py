@@ -7,6 +7,7 @@ from repro.core.collator import RetrievalCollator
 from repro.core.config import DataArguments
 from repro.core.embedding_cache import EmbeddingCache
 from repro.data.graph import CSRGraph, NeighborSampler, make_random_graph
+from repro.data import tokenizer as tokenizer_mod
 from repro.data.tokenizer import HashTokenizer
 
 
@@ -43,9 +44,10 @@ def test_tokenizer_truncate_to_empty():
         == [[], []]
 
 
-def test_batch_encode_ids_matches_scalar_encode():
-    """The np.unique vectorized batch path must reproduce the scalar
-    encode() exactly — same ids, same truncation/eos semantics."""
+def test_batch_encode_ids_matches_scalar_encode(monkeypatch):
+    """The batch path must reproduce the scalar encode() exactly (same
+    ids, same truncation/eos semantics), with a warm, a cold and a full
+    id cache."""
     t = HashTokenizer(512)
     texts = ["Hello, World!", "", "a a a a a", "punct...!?", "x" * 40,
              " ".join(f"tok{i}" for i in range(30)), "ümlaut çedilla",
@@ -56,6 +58,50 @@ def test_batch_encode_ids_matches_scalar_encode():
             want = [t.encode(x, max_len, eos) for x in texts]
             assert t.batch_encode_ids(texts, max_len, eos) == want
             assert fresh.batch_encode_ids(texts, max_len, eos) == want
+
+    # a cache at its cap stores nothing more: every distinct token of
+    # the batch is still hashed exactly once, through the per-call memo
+    monkeypatch.setattr(tokenizer_mod, "_CACHE_CAP", 4)
+    for max_len in (None, 0, 3, 16):
+        for eos in (False, True):
+            full = HashTokenizer(50304)
+            full.encode("filler0 filler1 filler2 filler3")
+            assert len(full._cache) == 4
+            want = [HashTokenizer(50304).encode(x, max_len, eos)
+                    for x in texts]
+            got = full.batch_encode_ids(texts, max_len, eos)
+            assert got == want
+            assert len(full._cache) == 4
+            distinct = {i for row in got for i in row} - {full.eos_id}
+            assert full.stats["hashed"] == len(distinct)
+
+
+@settings(max_examples=60, deadline=None)
+@given(st.lists(st.text(max_size=60), max_size=6),
+       st.one_of(st.none(), st.integers(0, 40)), st.booleans())
+def test_batch_encode_ids_matches_encode_property(texts, max_len, eos):
+    want = [HashTokenizer(300).encode(x, max_len, eos) for x in texts]
+    t = HashTokenizer(300)
+    assert t.batch_encode_ids(texts, max_len, eos) == want
+    assert t.batch_encode_ids(texts, max_len, eos) == want      # warm
+
+
+def test_batch_encode_ids_counts_tokens_and_hashes():
+    t = HashTokenizer(50304)
+    texts = ["the cat sat on the mat", "", "The CAT, again!", "mat mat"]
+    n_occ = sum(len(t.encode(x)) for x in texts)
+    assert t.stats == {"tokens": 0, "hashed": 0}
+    t2 = HashTokenizer(50304)
+    t2.batch_encode_ids(texts)
+    # the, cat, sat, on, mat, ",", again, "!"
+    assert t2.stats == {"tokens": n_occ, "hashed": 8}
+    t2.batch_encode_ids(texts)                     # every id cached now
+    assert t2.stats == {"tokens": 2 * n_occ, "hashed": 8}
+    # tokens past the cut are never mapped
+    t3 = HashTokenizer(50304)
+    t3.batch_encode_ids(texts, max_len=2)
+    assert t3.stats["tokens"] == sum(min(len(t.encode(x)), 2)
+                                     for x in texts)
 
 
 def test_batch_encode_matches_legacy_padding():

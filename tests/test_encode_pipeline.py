@@ -2,6 +2,9 @@
 compile bound, and pipeline-vs-legacy ranking equivalence across the
 score_impl x heap_impl x W matrix (ISSUE 5 acceptance)."""
 
+import sys
+import threading
+
 import numpy as np
 import pytest
 
@@ -125,6 +128,37 @@ def test_tokenize_workers_match_serial(varied_texts):
     fanned = EncodePipeline(_sum_encoder(), tok, tokenizer_workers=4)
     assert fanned.tokenize(varied_texts, 24) == \
         serial.tokenize(varied_texts, 24)
+
+
+def test_tokenize_shared_cold_memo_across_threads(varied_texts):
+    """Two threads tokenize through one pipeline (two workers each) and
+    so share one cold id cache: their rows equal a serial fresh one's."""
+    texts = varied_texts * 8
+    want = EncodePipeline(_sum_encoder(), HashTokenizer(4096),
+                          tokenizer_workers=1).tokenize(texts, 40)
+    tok = HashTokenizer(4096)
+    pipe = EncodePipeline(_sum_encoder(), tok, tokenizer_workers=2)
+    got = [None, None]
+    start = threading.Barrier(2)
+
+    def work(i):
+        start.wait()
+        got[i] = pipe.tokenize(texts, 40)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)        # interleave the threads finely
+    try:
+        threads = [threading.Thread(target=work, args=(i,))
+                   for i in range(2)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(th.is_alive() for th in threads)
+    assert got[0] == want and got[1] == want
+    assert tok.stats["tokens"] == 2 * sum(len(r) for r in want)
 
 
 # -- evaluator-level equivalence: pipeline vs legacy per-batch path -----------
